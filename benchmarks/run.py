@@ -240,7 +240,9 @@ def main() -> None:
                             fig9_batch_times, fig11_served_latency,
                             policies, replicas, roofline, superstep,
                             table1_throughput, tails)
+    from repro.core.engine import init_compile_cache
 
+    init_compile_cache()
     modules = {
         "table1": lambda: table1_throughput.run(),
         "fig4": lambda: fig4_latency_bound.run(
@@ -286,12 +288,16 @@ def main() -> None:
 
     json_dir = Path(args.json_dir)
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in modules.items():
         t0 = time.perf_counter()
         try:
             rows = list(fn())
         except Exception as e:  # noqa: BLE001
+            # report the module and carry on with the rest; the exit
+            # code below still says the run failed
             print(f"{name}/ERROR,0,{type(e).__name__}: {e}", flush=True)
+            failed.append(name)
             continue
         wall_s = time.perf_counter() - t0
         for row in rows:
@@ -313,6 +319,8 @@ def main() -> None:
         (json_dir / "BENCH_compare.txt").write_text(report)
         if regressed:
             sys.exit(1)
+    if failed:
+        sys.exit(f"module(s) raised: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -75,7 +75,7 @@ def run(n_batches: int = 3000,
                                   results["pallas"].hist)
                    and np.array_equal(results["lax"].n_jobs,
                                       results["pallas"].n_jobs))
-        return {"auto_backend": resolve_backend(None, n_bins=64),
+        return {"auto_backend": resolve_backend(None),
                 "lax_s": t_lax / 1e6, "pallas_s": t_pallas / 1e6,
                 "speedup": t_lax / t_pallas,
                 "bitwise_equal": bool(bitwise)}

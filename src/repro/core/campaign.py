@@ -48,7 +48,7 @@ a checkpointed prefix, so a killed-and-resumed campaign is also
 bitwise-identical to an uninterrupted one.
 
 Accumulator precision: the fold runs in float64/int64 (built and
-called inside ``jax.experimental.enable_x64`` scopes, the
+called inside ``jax.enable_x64(True)`` scopes, the
 ``chain_solver`` pattern — the global x64 flag stays off).  The sim
 kernels themselves are dispatched OUTSIDE those scopes and stay the
 same float32 programs ``sweep`` compiles.
@@ -1045,14 +1045,13 @@ def _run_pipelined(grid, plan_fn, kind, n, c_size, n_chunks, padded,
                    fault_plan, fault_retries, fault_backoff_s,
                    kill_after, quarantined):
     import jax
-    from jax.experimental import enable_x64
 
     # the revisited PR 5 decision: donate the accumulator on
     # accelerator backends only (CPU donation is a warning no-op)
     donate = jax.default_backend() != "cpu"
     if acc_host is None:
         acc_host = _init_acc(n_bins, k_top)
-    with enable_x64():
+    with jax.enable_x64(True):
         acc = jax.device_put(acc_host)
 
     last_chunk = n_chunks if stop_after is None \
@@ -1156,7 +1155,7 @@ def _run_pipelined(grid, plan_fn, kind, n, c_size, n_chunks, padded,
             # still advances chunks_done past the quarantined chunk
             ckpt_ref = None
             if is_ckpt:
-                with enable_x64():
+                with jax.enable_x64(True):
                     ckpt_ref = (jax.tree_util.tree_map(
                         lambda a: a + 0, acc) if donate else acc)
             pending.append((ci, None, ckpt_ref,
@@ -1172,7 +1171,7 @@ def _run_pipelined(grid, plan_fn, kind, n, c_size, n_chunks, padded,
         poison = (fault_plan is not None
                   and fault_plan.roll("nan", ci, attempt))
         lam_dev = engine.pad_tail(plan.params["lam"], pad2)
-        with enable_x64():
+        with jax.enable_x64(True):
             fold = _build_fold(c_size + pad2, n_bins, k_top,
                                plan.has_loss, plan.sketch, True,
                                donate)
@@ -1191,7 +1190,7 @@ def _run_pipelined(grid, plan_fn, kind, n, c_size, n_chunks, padded,
                                               dtype=np.int64),
                                     np.int64(n_valid))
         if is_ckpt:
-            with enable_x64():
+            with jax.enable_x64(True):
                 ckpt_ref = (jax.tree_util.tree_map(lambda a: a + 0, acc)
                             if donate else acc)
         else:
@@ -1255,7 +1254,6 @@ def _run_adaptive(grid, plan_fn, kind, n, c_size, n_chunks, padded,
     the final pass with the persisted ``triage.npz`` as its basis."""
     import io
     import jax
-    from jax.experimental import enable_x64
 
     donate = jax.default_backend() != "cpu"
     base_kw = {k: v for k, v in kernel_kw.items() if k != steps_kw}
@@ -1364,7 +1362,7 @@ def _run_adaptive(grid, plan_fn, kind, n, c_size, n_chunks, padded,
         stats["n_jobs"] = np.zeros(n, np.int64)
 
     # ---- phase 2: compacted, tiered final pass (the only fold) ------
-    with enable_x64():
+    with jax.enable_x64(True):
         acc = jax.device_put(acc_host)
     f_start = max(start_chunk - n_chunks, 0)
     last_f = len(fchunks) if stop_after is None \
@@ -1420,7 +1418,7 @@ def _run_adaptive(grid, plan_fn, kind, n, c_size, n_chunks, padded,
         lam_dev = engine.pad_tail(plan.params["lam"], pad2)
         gidx = (np.concatenate([gsel, np.repeat(gsel[-1:], pad2)])
                 if pad2 else gsel)
-        with enable_x64():
+        with jax.enable_x64(True):
             fold = _build_fold(c_size + pad2, n_bins, k_top,
                                plan.has_loss, plan.sketch, True,
                                donate)
@@ -1436,7 +1434,7 @@ def _run_adaptive(grid, plan_fn, kind, n, c_size, n_chunks, padded,
                    and ((fi + 1) % max(checkpoint_every, 1) == 0
                         or fi == last_f - 1))
         if is_ckpt:
-            with enable_x64():
+            with jax.enable_x64(True):
                 ckpt_ref = (jax.tree_util.tree_map(lambda a: a + 0,
                                                    acc)
                             if donate else acc)

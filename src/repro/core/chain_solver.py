@@ -50,7 +50,7 @@ adaptive truncation stays small precisely because the ∞-chain's queue
 is short.
 
 JAX-free at import time: the jit kernel is built lazily inside
-``grid_solve`` (and runs under ``jax.experimental.enable_x64`` so the
+``grid_solve`` (and runs under ``jax.enable_x64(True)`` so the
 rest of the process keeps its default float32 semantics).
 """
 from __future__ import annotations
@@ -374,7 +374,7 @@ def _build_grid_kernel(K: int, V: int, D: int):
             "_build_grid_kernel called outside an enable_x64 scope; "
             "build-time jnp constants would be float32 and silently "
             "truncate the GTH recursion (wrap the build + dispatch in "
-            "jax.experimental.enable_x64)")
+            "jax.enable_x64(True))")
 
     f64, i32 = jnp.float64, jnp.int32
     # kept as NumPy here: the factorial table is the one constant big
@@ -533,12 +533,12 @@ def grid_solve(lams, alphas, tau0s, b_maxes, K: int, *,
     if method != "jax":
         raise ValueError(f"unknown grid method {method!r}")
 
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     V, D = _grid_shapes(lams, alphas, tau0s, b_maxes, K)
     chunk = min(cells_per_dispatch, n)
-    with enable_x64():
+    with jax.enable_x64(True):
         # build INSIDE the x64 scope: the builder bakes trace-time
         # constants, and enforces this placement with a RuntimeError
         kernel = _build_grid_kernel(K, V, D)

@@ -94,15 +94,16 @@ from __future__ import annotations
 
 import os
 from collections import OrderedDict
+from pathlib import Path
 from typing import (Any, Callable, Dict, NamedTuple, Optional, Sequence,
                     Union)
 
 import numpy as np
 
-__all__ = ["enable_host_devices", "point_keys", "point_keys_at",
-           "welford_block", "resolve_shards",
+__all__ = ["enable_host_devices", "init_compile_cache", "point_keys",
+           "point_keys_at", "welford_block", "resolve_shards",
            "shard_kernel", "pad_tail", "dispatch", "dispatch_device",
-           "KernelPlan", "exp_gaps",
+           "KernelPlan", "point_sum", "exp_gaps",
            "exp_offsets", "fifo_append", "fifo_pop_shift",
            "accept_window", "push_poisson_window",
            "push_poisson_window_loss", "renege_prefix", "orbit_draws",
@@ -126,6 +127,23 @@ def enable_host_devices(n: Optional[int] = None) -> None:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={n}").strip()
+
+
+def init_compile_cache() -> str:
+    """Give JAX's persistent compilation cache a fixed home, and return
+    it.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself
+    and nothing is changed; otherwise the cache goes to ``.jax_cache``
+    at the root of this checkout.  The path is part of each entry's
+    key, so it never depends on a temp name, a PID or the time.  Call
+    it from an entry point, before the first compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # ---------------------------------------------------------------------------
@@ -239,17 +257,16 @@ def shard_kernel(vm: Callable, n_dev: int, *,
 
     if n_dev <= 1:
         return jax.jit(vm, donate_argnums=tuple(donate))
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec
 
     mesh = Mesh(np.array(jax.devices()[:n_dev]), ("points",))
     spec = PartitionSpec("points")
-    # check_rep=False: the kernels are purely per-point vmaps (no
-    # collectives), so shard_map's replication-rule check adds nothing —
-    # and pallas_call has no replication rule at all, which used to make
+    # check_vma=False: the kernels are purely per-point vmaps (no
+    # collectives), so shard_map's varying-axes check adds nothing —
+    # and pallas_call has no such rule at all, which used to make
     # every fused-pallas dispatch crash under a multi-device mesh
-    return jax.jit(shard_map(vm, mesh=mesh, in_specs=(spec, spec),
-                             out_specs=spec, check_rep=False),
+    return jax.jit(jax.shard_map(vm, mesh=mesh, in_specs=(spec, spec),
+                                 out_specs=spec, check_vma=False),
                    donate_argnums=tuple(donate))
 
 
@@ -325,6 +342,27 @@ def dispatch(kernel: Callable, params: Dict[str, Any], keys, n: int,
 # ---------------------------------------------------------------------------
 # trace-time kernel building blocks (call inside a jit kernel)
 # ---------------------------------------------------------------------------
+
+def point_sum(x):
+    """Sum of a per-point float vector in a fixed pairwise order.
+
+    ``jnp.sum`` leaves the addition order to the backend, which may
+    vectorize a vmapped reduction differently for different point
+    counts, so a point's f32 total could change in its last ulp with the
+    dispatch width or shard count.  Halving with elementwise adds pins
+    the order, keeping per-point results bitwise invariant to how the
+    grid is split (the ``point_keys`` contract)."""
+    import jax.numpy as jnp
+
+    n = x.shape[-1]
+    width = 1 << max(n - 1, 0).bit_length()
+    if width > n:
+        x = jnp.concatenate([x, jnp.zeros((width - n,), x.dtype)])
+    while width > 1:
+        width //= 2
+        x = x[:width] + x[width:]
+    return x[0]
+
 
 def exp_gaps(key, n: int, rate):
     """n i.i.d. Exp(rate) inter-arrival gaps (one vectorized draw)."""

@@ -344,8 +344,8 @@ def _build_gen_kernel(n_steps: int, warmup: int, s_cap: int, q_cap: int,
                     * mttr
                 pre = jnp.cumprod((e_blk < w).astype(f32))
                 n_rst = jnp.sum(pre).astype(i32)
-                lost_rst = jnp.sum(pre * e_blk)
-                rep_rst = jnp.sum(pre * r_blk)
+                lost_rst = engine.point_sum(pre * e_blk)
+                rep_rst = engine.point_sum(pre * r_blk)
                 e1, r1 = e_blk[0], r_blk[0]
                 aborts = fail_on & is_drop & (e1 < w)
                 n_f = jnp.where(
@@ -428,7 +428,7 @@ def _build_gen_kernel(n_steps: int, warmup: int, s_cap: int, q_cap: int,
             mf = meas.astype(f32)
             bf = b.astype(f32)
             n_fin = jnp.sum(fin.astype(i32))
-            lat_sum = lat_sum + mf * lats.sum()
+            lat_sum = lat_sum + mf * engine.point_sum(lats)
             lat_n = lat_n + jnp.where(meas, n_fin, 0)
             if has_fail:
                 # decode-step stats count completed runs only; busy is
@@ -720,8 +720,7 @@ def gen_plan(grid: GenGrid, *, n_steps: int = 4096,
     if sketch:
         n_bins = SKETCH_BINS
     n = len(grid)
-    ss_backend = _ss.resolve_backend(superstep_backend,
-                                     n_bins=int(n_bins), n_points=n)
+    ss_backend = _ss.resolve_backend(superstep_backend)
     n_dev = engine.resolve_shards(shard, n)
     if metrics_tap is not None:
         # io_callback under shard_map is outside the pinned-jax

@@ -293,8 +293,8 @@ def _build_kernel(n_batches: int, warmup: int, q_cap: int, a_cap: int,
                     * mttr
                 pre = jnp.cumprod((e_blk < s).astype(f32))
                 n_rst = jnp.sum(pre).astype(i32)
-                lost_rst = jnp.sum(pre * e_blk)
-                rep_rst = jnp.sum(pre * r_blk)
+                lost_rst = engine.point_sum(pre * e_blk)
+                rep_rst = engine.point_sum(pre * r_blk)
                 # fail-drop: the batch aborts at its first failure
                 # epoch; only the repair follows (jobs are filed
                 # through the abandonment path at the departure epoch)
@@ -403,7 +403,7 @@ def _build_kernel(n_batches: int, warmup: int, q_cap: int, a_cap: int,
                 # productive execution only — repairs and lost restart
                 # work are tracked separately (down_time, lost_work)
                 mfc = mf * (1.0 - aborts.astype(jnp.float32))
-                lat_sum = lat_sum + mfc * lats.sum()
+                lat_sum = lat_sum + mfc * engine.point_sum(lats)
                 lat_n = lat_n + jnp.where(meas & ~aborts, b, 0)
                 sum_b = sum_b + mfc * bf
                 sum_b2 = sum_b2 + mfc * bf * bf
@@ -419,7 +419,7 @@ def _build_kernel(n_batches: int, warmup: int, q_cap: int, a_cap: int,
                 dtime = dtime + mf * rep
                 lwork = lwork + mf * lost
             else:
-                lat_sum = lat_sum + mf * lats.sum()
+                lat_sum = lat_sum + mf * engine.point_sum(lats)
                 lat_n = lat_n + jnp.where(meas, b, 0)
                 sum_b = sum_b + mf * bf
                 sum_b2 = sum_b2 + mf * bf * bf
@@ -644,8 +644,7 @@ def sweep_plan(grid: SweepGrid, *, n_batches: int = 3000,
     if sketch:
         n_bins = SKETCH_BINS
     n = len(grid)
-    ss_backend = _ss.resolve_backend(superstep_backend,
-                                     n_bins=int(n_bins), n_points=n)
+    ss_backend = _ss.resolve_backend(superstep_backend)
     n_dev = engine.resolve_shards(shard, n)
     if metrics_tap is not None:
         # io_callback under shard_map is outside the pinned-jax
@@ -1166,8 +1165,8 @@ def _build_fleet_kernel(n_steps: int, warmup: int, k_max: int, q_cap: int,
                     * mttr
                 pre = jnp.cumprod((e_blk < s).astype(f32))
                 n_rst = jnp.sum(pre).astype(i32)
-                lost_rst = jnp.sum(pre * e_blk)
-                rep_rst = jnp.sum(pre * r_blk)
+                lost_rst = engine.point_sum(pre * e_blk)
+                rep_rst = engine.point_sum(pre * r_blk)
                 e1, r1 = e_blk[0], r_blk[0]
                 aborts = fail_on & is_drop & (e1 < s)
                 n_f = jnp.where(
@@ -1236,7 +1235,7 @@ def _build_fleet_kernel(n_steps: int, warmup: int, k_max: int, q_cap: int,
                 # completed-batch stats only; busy counts productive
                 # execution (repairs → down_time, rework → lost_work)
                 mfc = mf * (1.0 - aborts.astype(f32))
-                lat_sum = lat_sum + mfc * lats.sum()
+                lat_sum = lat_sum + mfc * engine.point_sum(lats)
                 lat_n = lat_n + jnp.where(mstart & ~aborts, b, 0)
                 sum_b = sum_b + mfc * bf
                 sum_b2 = sum_b2 + mfc * bf * bf
@@ -1249,7 +1248,7 @@ def _build_fleet_kernel(n_steps: int, warmup: int, k_max: int, q_cap: int,
                 jobs_rep = jobs_rep \
                     + jnp.where(oh & mstart & ~aborts, b, 0)
             else:
-                lat_sum = lat_sum + mf * lats.sum()
+                lat_sum = lat_sum + mf * engine.point_sum(lats)
                 lat_n = lat_n + jnp.where(mstart, b, 0)
                 sum_b = sum_b + mf * bf
                 sum_b2 = sum_b2 + mf * bf * bf
@@ -1565,8 +1564,7 @@ def fleet_plan(grid: FleetGrid, *, n_steps: int = 6000,
     if sketch:
         n_bins = SKETCH_BINS
     n = len(grid)
-    ss_backend = _ss.resolve_backend(superstep_backend,
-                                     n_bins=int(n_bins), n_points=n)
+    ss_backend = _ss.resolve_backend(superstep_backend)
     n_dev = engine.resolve_shards(shard, n)
     if metrics_tap is not None:
         # io_callback under shard_map is outside the pinned-jax

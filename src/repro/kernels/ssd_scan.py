@@ -19,11 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pallas renamed TPUCompilerParams -> CompilerParams across JAX releases;
-# accept either so the kernels import on both sides of the rename
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, h_ref, *,
             cs: int, nc: int):
@@ -109,7 +104,7 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
         out_specs=pl.BlockSpec((1, chunk, hd), xh_map),
         out_shape=jax.ShapeDtypeStruct((b * nh, s, hd), x.dtype),
         scratch_shapes=[pltpu.VMEM((hd, ds), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(af, xf, dtf, bf, cf)

@@ -10,6 +10,7 @@ import argparse
 
 from repro.configs import get_config, list_archs, reduced as reduce_cfg
 from repro.core import BatchAllWaiting, CappedBatch, TimeoutBatch, phi
+from repro.core.engine import init_compile_cache
 from repro.serving import InferenceEngine
 
 POLICIES = {
@@ -23,7 +24,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b", choices=list_archs())
     ap.add_argument("--full", action="store_true",
-                    help="full config (TPU cluster); default reduced")
+                    help="published widths (one accelerator device); "
+                         "default reduced")
     ap.add_argument("--workload", default="forward",
                     choices=["forward", "generate"])
     ap.add_argument("--rho", type=float, default=0.5)
@@ -32,6 +34,7 @@ def main() -> None:
     ap.add_argument("--policy", default="batch-all", choices=list(POLICIES))
     args = ap.parse_args()
 
+    init_compile_cache()
     cfg = get_config(args.arch)
     if not args.full:
         cfg = reduce_cfg(cfg)

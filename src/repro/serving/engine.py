@@ -1,8 +1,8 @@
 """Dynamic-batching inference engine — the system the paper characterizes.
 
 The engine executes REAL JAX models (the reduced assigned architectures on
-CPU; the full ones on a TPU mesh via launch/serve.py) under the paper's
-batch-service discipline:
+CPU; the full ones on one accelerator device via launch/serve.py --full)
+under the paper's batch-service discipline:
 
 - requests arrive (Poisson load generator, MLPerf-Server-Scenario style),
 - whenever the server is free, a batching policy (default: the paper's
@@ -144,9 +144,12 @@ class InferenceEngine:
 
     # ------------------------------------------------------------------
     def run_batch(self, b: int) -> float:
-        """Execute one batch of b requests; return wall seconds."""
+        """Execute one batch of b requests; return wall seconds.  The
+        inputs reach the device before the clock starts, and the clock
+        stops when the outputs are ready, so the time is the batch's
+        device service time plus dispatch."""
         bb = self.bucket_of(b)
-        batch = self._make_batch(bb)
+        batch = jax.block_until_ready(self._make_batch(bb))
         t0 = time.perf_counter()
         out = self._fns[bb](self.params, batch)
         jax.block_until_ready(out)

@@ -257,9 +257,8 @@ class TestX64Discipline:
     def test_every_band_path_output_is_float64(self):
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
-        with enable_x64():
+        with jax.enable_x64(True):
             kernel = cs._build_grid_kernel(64, 16, 8)
             out = jax.eval_shape(
                 kernel,
