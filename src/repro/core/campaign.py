@@ -132,7 +132,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.core import engine
+from repro.core import engine, metrics
 from repro.core.grid import FleetGrid, GenGrid, SweepGrid
 from repro.core.hist import (SKETCH_BINS, hist_edges, hist_percentiles,
                              sketch_edges)
@@ -539,7 +539,6 @@ class CampaignResult:
     sketch: bool
     acc: Dict[str, np.ndarray] = field(repr=False)
     rows: List[dict] = field(repr=False)
-    wall_s: float = 0.0
     peak_host_result_bytes: int = 0
     serial_compile_shapes: int = 0
     tapped_chunks: int = 0
@@ -667,6 +666,15 @@ def _atomic_write(path: Path, data: bytes) -> None:
 
 class _Store:
     """manifest.json + accumulator.npz + chunks.jsonl under out_dir.
+
+    ``chunks.jsonl`` holds one row per drained chunk: its index, first
+    point, points, padding, retries and summary counts.  Pipelined rows
+    time the chunk from its spans: ``host_s`` is the host's work to
+    issue it (``campaign.plan``, ``campaign.dispatch`` and
+    ``campaign.fold``: slicing, planning, dispatch and the fold's
+    enqueue) and ``wait_s`` the time the host blocked on its summary
+    (``campaign.wait``).  Serial and adaptive rows give ``wall_s``,
+    the chunk's blocking time from dispatch to its summary.
 
     Checkpoints are integrity-checked and two-generation: the
     manifest records the accumulator's sha256, and the previous
@@ -891,125 +899,125 @@ def campaign(grid, *, chunk_size: int = 4096, mode: str = "pipelined",
     plan_fn, caps_fn, steps_kw = _kind_fns(kind)
     n = len(grid)
     c_size, n_chunks, padded = plan_chunks(n, chunk_size)
-    if mode not in ("pipelined", "serial", "adaptive"):
-        raise ValueError(f"unknown campaign mode {mode!r}")
-    if mode != "adaptive" and (pilot is not None or target_ci is not None
-                               or refine_budget is not None):
-        raise ValueError("pilot/target_ci/refine_budget require "
-                         "mode='adaptive'")
-    if mode != "pipelined" and (fault_plan is not None
-                                or _kill_after_chunks is not None):
-        raise ValueError("fault_plan/_kill_after_chunks target the "
-                         "streaming driver (mode='pipelined')")
-    if fault_retries < 0:
-        raise ValueError(f"fault_retries must be >= 0 "
-                         f"(got {fault_retries})")
-    if sketch:
-        n_bins = SKETCH_BINS
-    pinned = dict(caps) if caps is not None else caps_fn(grid)
+    with metrics.span("campaign", points=n, chunks=n_chunks,
+                      mode=mode):
+        if mode not in ("pipelined", "serial", "adaptive"):
+            raise ValueError(f"unknown campaign mode {mode!r}")
+        if mode != "adaptive" and (pilot is not None or target_ci is not None
+                                   or refine_budget is not None):
+            raise ValueError("pilot/target_ci/refine_budget require "
+                             "mode='adaptive'")
+        if mode != "pipelined" and (fault_plan is not None
+                                    or _kill_after_chunks is not None):
+            raise ValueError("fault_plan/_kill_after_chunks target the "
+                             "streaming driver (mode='pipelined')")
+        if fault_retries < 0:
+            raise ValueError(f"fault_retries must be >= 0 "
+                             f"(got {fault_retries})")
+        if sketch:
+            n_bins = SKETCH_BINS
+        pinned = dict(caps) if caps is not None else caps_fn(grid)
 
-    n_max = int(kernel_kw.get(steps_kw, _DEFAULT_CYCLES[kind]))
-    if mode == "adaptive":
-        if metrics_tap is not None:
-            raise ValueError("mode='adaptive' does not support "
-                             "metrics_tap")
-        if (target_ci is None) == (refine_budget is None):
-            raise ValueError("mode='adaptive' needs exactly one of "
-                             "target_ci / refine_budget")
-        q = _CYCLE_QUANTUM[kind]
-        if pilot is None:
-            pilot = min(n_max, max(4 * q, n_max // 16))
-        pilot = -(-int(pilot) // q) * q      # round up to the quantum
-        if not 0 < pilot <= n_max:
-            raise ValueError(f"pilot={pilot} must be in (0, "
-                             f"{steps_kw}={n_max}]")
-
-    config = {"kind": kind, "mode": mode, "n_points": n,
-              "chunk_size": c_size,
-              "n_bins": int(n_bins), "sketch": bool(sketch),
-              "seed": int(seed), "k_top": int(k_top),
-              "caps": {k: int(v) for k, v in sorted(pinned.items())},
-              "kernel_kw": {k: repr(v)
-                            for k, v in sorted(kernel_kw.items())}}
-    if mode == "adaptive":
-        config["adaptive"] = {
-            "pilot": int(pilot), "n_max": int(n_max),
-            "target_ci": (None if target_ci is None
-                          else float(target_ci)),
-            "refine_budget": (None if refine_budget is None
-                              else int(refine_budget)),
-            "safety": float(safety)}
-    if fault_plan is not None:
-        # part of the config fingerprint: a resume must replay the
-        # SAME fault schedule or bitwise parity is meaningless
-        config["fault_plan"] = fault_plan.to_config()
-    grid_sha = _grid_sha(grid)
-
-    store = _Store(Path(out_dir)) if out_dir is not None else None
-    start_chunk = 0
-    rows: List[dict] = []
-    acc_host: Optional[Dict[str, np.ndarray]] = None
-    quarantined: List[dict] = []
-    fault_events: List[dict] = []
-    if resume:
-        if store is None:
-            raise ValueError("resume=True needs out_dir")
-        man = store.load_manifest()
-        if man is None:
-            raise FileNotFoundError(
-                f"resume=True but no manifest under {out_dir}")
-        if man.get("grid_sha") != grid_sha or man.get("config") != config:
-            raise ValueError(
-                "resume manifest does not match this campaign (grid "
-                "or config changed); start fresh in a new out_dir")
-        acc_host, start_chunk, fault_events = \
-            store.load_acc_checked(man)
-        # quarantine entries at or past the resume point describe
-        # chunks the resume recomputes — drop them like stale rows
-        quarantined = [q for q in man.get("quarantined", [])
-                       if q["chunk"] < start_chunk]
-        rows = store.truncate_rows(start_chunk)
-
-    t0 = time.perf_counter()
-    try:
+        n_max = int(kernel_kw.get(steps_kw, _DEFAULT_CYCLES[kind]))
         if mode == "adaptive":
-            result = _run_adaptive(grid, plan_fn, kind, n, c_size,
-                                   n_chunks, padded, n_bins, sketch,
-                                   seed, shard, superstep_backend,
-                                   pinned, kernel_kw, steps_kw, k_top,
-                                   pipeline_depth, checkpoint_every,
-                                   store, config, grid_sha, start_chunk,
-                                   rows, acc_host, stop_after_chunks,
-                                   pilot, target_ci, refine_budget,
-                                   n_max, safety, keep_point_stats)
-        elif mode == "serial":
-            result = _run_serial(grid, plan_fn, caps_fn, kind, n,
-                                 c_size, n_chunks, padded, n_bins,
-                                 sketch, seed, shard,
-                                 superstep_backend, kernel_kw,
-                                 steps_kw, k_top, store, config,
-                                 grid_sha, start_chunk, rows, acc_host,
-                                 stop_after_chunks, metrics_tap)
-        else:
-            result = _run_pipelined(grid, plan_fn, kind, n, c_size,
-                                    n_chunks, padded, n_bins, sketch,
-                                    seed, shard, superstep_backend,
-                                    pinned, kernel_kw, k_top,
-                                    pipeline_depth, checkpoint_every,
-                                    store, config, grid_sha,
-                                    start_chunk, rows, acc_host,
-                                    stop_after_chunks, metrics_tap,
-                                    tap_every, fault_plan,
-                                    fault_retries, fault_backoff_s,
-                                    _kill_after_chunks, quarantined)
-    finally:
+            if metrics_tap is not None:
+                raise ValueError("mode='adaptive' does not support "
+                                 "metrics_tap")
+            if (target_ci is None) == (refine_budget is None):
+                raise ValueError("mode='adaptive' needs exactly one of "
+                                 "target_ci / refine_budget")
+            q = _CYCLE_QUANTUM[kind]
+            if pilot is None:
+                pilot = min(n_max, max(4 * q, n_max // 16))
+            pilot = -(-int(pilot) // q) * q      # round up to the quantum
+            if not 0 < pilot <= n_max:
+                raise ValueError(f"pilot={pilot} must be in (0, "
+                                 f"{steps_kw}={n_max}]")
+
+        config = {"kind": kind, "mode": mode, "n_points": n,
+                  "chunk_size": c_size,
+                  "n_bins": int(n_bins), "sketch": bool(sketch),
+                  "seed": int(seed), "k_top": int(k_top),
+                  "caps": {k: int(v) for k, v in sorted(pinned.items())},
+                  "kernel_kw": {k: repr(v)
+                                for k, v in sorted(kernel_kw.items())}}
+        if mode == "adaptive":
+            config["adaptive"] = {
+                "pilot": int(pilot), "n_max": int(n_max),
+                "target_ci": (None if target_ci is None
+                              else float(target_ci)),
+                "refine_budget": (None if refine_budget is None
+                                  else int(refine_budget)),
+                "safety": float(safety)}
+        if fault_plan is not None:
+            # part of the config fingerprint: a resume must replay the
+            # SAME fault schedule or bitwise parity is meaningless
+            config["fault_plan"] = fault_plan.to_config()
+        grid_sha = _grid_sha(grid)
+
+        store = _Store(Path(out_dir)) if out_dir is not None else None
+        start_chunk = 0
+        rows: List[dict] = []
+        acc_host: Optional[Dict[str, np.ndarray]] = None
+        quarantined: List[dict] = []
+        fault_events: List[dict] = []
+        if resume:
+            if store is None:
+                raise ValueError("resume=True needs out_dir")
+            man = store.load_manifest()
+            if man is None:
+                raise FileNotFoundError(
+                    f"resume=True but no manifest under {out_dir}")
+            if man.get("grid_sha") != grid_sha or man.get("config") != config:
+                raise ValueError(
+                    "resume manifest does not match this campaign (grid "
+                    "or config changed); start fresh in a new out_dir")
+            acc_host, start_chunk, fault_events = \
+                store.load_acc_checked(man)
+            # quarantine entries at or past the resume point describe
+            # chunks the resume recomputes — drop them like stale rows
+            quarantined = [q for q in man.get("quarantined", [])
+                           if q["chunk"] < start_chunk]
+            rows = store.truncate_rows(start_chunk)
+
+        try:
+            if mode == "adaptive":
+                result = _run_adaptive(grid, plan_fn, kind, n, c_size,
+                                       n_chunks, padded, n_bins, sketch,
+                                       seed, shard, superstep_backend,
+                                       pinned, kernel_kw, steps_kw, k_top,
+                                       pipeline_depth, checkpoint_every,
+                                       store, config, grid_sha, start_chunk,
+                                       rows, acc_host, stop_after_chunks,
+                                       pilot, target_ci, refine_budget,
+                                       n_max, safety, keep_point_stats)
+            elif mode == "serial":
+                result = _run_serial(grid, plan_fn, caps_fn, kind, n,
+                                     c_size, n_chunks, padded, n_bins,
+                                     sketch, seed, shard,
+                                     superstep_backend, kernel_kw,
+                                     steps_kw, k_top, store, config,
+                                     grid_sha, start_chunk, rows, acc_host,
+                                     stop_after_chunks, metrics_tap)
+            else:
+                result = _run_pipelined(grid, plan_fn, kind, n, c_size,
+                                        n_chunks, padded, n_bins, sketch,
+                                        seed, shard, superstep_backend,
+                                        pinned, kernel_kw, k_top,
+                                        pipeline_depth, checkpoint_every,
+                                        store, config, grid_sha,
+                                        start_chunk, rows, acc_host,
+                                        stop_after_chunks, metrics_tap,
+                                        tap_every, fault_plan,
+                                        fault_retries, fault_backoff_s,
+                                        _kill_after_chunks, quarantined)
+        finally:
+            if store is not None:
+                store.close()
+        result.fault_events = fault_events + result.fault_events
         if store is not None:
-            store.close()
-    result.wall_s = time.perf_counter() - t0
-    result.fault_events = fault_events + result.fault_events
-    if store is not None:
-        result.out_dir = str(store.dir)
-    return result
+            result.out_dir = str(store.dir)
+        return result
 
 
 def _chunk_grid(grid, start: int, c_size: int, n: int):
@@ -1061,62 +1069,62 @@ def _run_pipelined(grid, plan_fn, kind, n, c_size, n_chunks, padded,
     tapped = 0
     drained = 0
 
-    meta_t0 = {}
-
     def drain_one():
         nonlocal peak_host, drained
         ci, summary_ref, ckpt_ref, meta = pending.pop(0)
-        skip = meta.pop("_skip", None)
+        wait_s = 0.0
         if summary_ref is not None:
-            summary = jax.device_get(summary_ref)  # blocks: chunk done
+            with metrics.span("campaign.wait", chunk=ci) as sp:
+                summary = jax.device_get(summary_ref)  # chunk done
+            wait_s = sp.seconds
         else:
             # dispatch-quarantined chunk: nothing was folded
             summary = {"points": 0, "jobs": 0, "buffer_dropped": 0,
                        "quarantined": meta["points"]}
-        host_bytes = _nbytes(summary) + meta.pop("_grid_bytes")
-        q_pts = int(summary.get("quarantined", 0))
-        if q_pts:
-            quarantined.append(
-                {"chunk": ci, "points": q_pts,
-                 "reason": "dispatch" if skip is not None
-                 else "nonfinite",
-                 **({"error": skip} if skip is not None else {})})
-        acc_np = None
-        if ckpt_ref is not None:
-            acc_np = jax.device_get(ckpt_ref)
-            host_bytes += _nbytes(acc_np)
-        row = {"chunk": ci, **meta,
-               **{k: int(v) for k, v in summary.items()},
-               "wall_s": round(time.perf_counter()
-                               - meta_t0.pop(ci), 4),
-               "host_bytes": host_bytes}
-        if store is not None:
-            store.append_row(row)
-            if acc_np is not None:
-                corrupt = (fault_plan is not None
-                           and fault_plan.roll("corrupt", ci))
-                store.checkpoint(
-                    {"version": MANIFEST_VERSION, "grid_sha": grid_sha,
-                     "config": config, "chunks_done": ci + 1,
-                     "n_chunks": n_chunks, "mode": "pipelined",
-                     "quarantined": [q for q in quarantined
-                                     if q["chunk"] <= ci]},
-                    acc_np, corrupt=corrupt)
-        rows.append(row)
-        peak_host = max(peak_host, host_bytes)
-        if metrics_tap is not None:
-            metrics_tap.observe_chunk(**{k: v for k, v in row.items()
-                                         if k != "host_bytes"})
-        drained += 1
-        if kill_after is not None and drained >= kill_after:
-            raise CampaignKilled(drained)
+        with metrics.span("campaign.drain", chunk=ci):
+            skip = meta.pop("_skip", None)
+            host_bytes = _nbytes(summary) + meta.pop("_grid_bytes")
+            q_pts = int(summary.get("quarantined", 0))
+            if q_pts:
+                quarantined.append(
+                    {"chunk": ci, "points": q_pts,
+                     "reason": "dispatch" if skip is not None
+                     else "nonfinite",
+                     **({"error": skip} if skip is not None else {})})
+            acc_np = None
+            if ckpt_ref is not None:
+                acc_np = jax.device_get(ckpt_ref)
+                host_bytes += _nbytes(acc_np)
+            row = {"chunk": ci, **meta,
+                   **{k: int(v) for k, v in summary.items()},
+                   "wait_s": round(wait_s, 6), "host_bytes": host_bytes}
+            if store is not None:
+                store.append_row(row)
+                if acc_np is not None:
+                    corrupt = (fault_plan is not None
+                               and fault_plan.roll("corrupt", ci))
+                    store.checkpoint(
+                        {"version": MANIFEST_VERSION, "grid_sha": grid_sha,
+                         "config": config, "chunks_done": ci + 1,
+                         "n_chunks": n_chunks, "mode": "pipelined",
+                         "quarantined": [q for q in quarantined
+                                         if q["chunk"] <= ci]},
+                        acc_np, corrupt=corrupt)
+            rows.append(row)
+            peak_host = max(peak_host, host_bytes)
+            if metrics_tap is not None:
+                metrics_tap.observe_chunk(**{k: v for k, v in row.items()
+                                             if k != "host_bytes"})
+            drained += 1
+            if kill_after is not None and drained >= kill_after:
+                raise CampaignKilled(drained)
 
     for ci in range(start_chunk, last_chunk):
         start = ci * c_size
-        cgrid, n_valid = _chunk_grid(grid, start, c_size, n)
+        n_valid = min(c_size, n - start)
         tap_this = (metrics_tap is not None and tap_every > 0
                     and ci % tap_every == 0)
-        meta_t0[ci] = time.perf_counter()
+        host_s = 0.0        # this chunk's plan, dispatch and fold spans
 
         # bounded retry with exponential backoff around the dispatch;
         # the attempt number feeds the injection hash, so retries
@@ -1129,16 +1137,24 @@ def _run_pipelined(grid, plan_fn, kind, n, c_size, n_chunks, padded,
                     raise CampaignFault(
                         f"injected dispatch failure (chunk {ci}, "
                         f"attempt {attempt})")
-                plan = plan_fn(cgrid, seed=seed, key_offset=start,
-                               n_bins=n_bins, sketch=sketch,
-                               shard=shard,
-                               superstep_backend=superstep_backend,
-                               metrics_tap=(metrics_tap if tap_this
-                                            else None),
-                               **pinned, **kernel_kw)
-                out, pad2 = engine.dispatch_device(
-                    plan.kernel, plan.params, plan.keys, plan.n,
-                    plan.n_dev)
+                with metrics.span("campaign.plan", chunk=ci) as sp:
+                    cgrid, _ = _chunk_grid(grid, start, c_size, n)
+                    plan = plan_fn(cgrid, seed=seed, key_offset=start,
+                                   n_bins=n_bins, sketch=sketch,
+                                   shard=shard,
+                                   superstep_backend=superstep_backend,
+                                   metrics_tap=(metrics_tap if tap_this
+                                                else None),
+                                   **pinned, **kernel_kw)
+                    sp.attrs.update(
+                        steps_per_superstep=plan.superstep_len,
+                        supersteps=plan.supersteps)
+                host_s += sp.seconds
+                with metrics.span("campaign.dispatch", chunk=ci) as sp:
+                    out, pad2 = engine.dispatch_device(
+                        plan.kernel, plan.params, plan.keys, plan.n,
+                        plan.n_dev)
+                host_s += sp.seconds
                 break
             except (CampaignFault, RuntimeError) as e:
                 if attempt >= fault_retries:
@@ -1162,6 +1178,7 @@ def _run_pipelined(grid, plan_fn, kind, n, c_size, n_chunks, padded,
                             {"start": start, "points": n_valid,
                              "padded": c_size - n_valid,
                              "tapped": False, "retries": attempt,
+                             "host_s": round(host_s, 6),
                              "_skip": skip, "_grid_bytes": 0}))
             while len(pending) > max(depth, 1):
                 drain_one()
@@ -1170,43 +1187,45 @@ def _run_pipelined(grid, plan_fn, kind, n, c_size, n_chunks, padded,
         tapped += bool(tap_this)
         poison = (fault_plan is not None
                   and fault_plan.roll("nan", ci, attempt))
-        lam_dev = engine.pad_tail(plan.params["lam"], pad2)
-        with jax.enable_x64(True):
-            fold = _build_fold(c_size + pad2, n_bins, k_top,
-                               plan.has_loss, plan.sketch, True,
-                               donate)
-            chunk = _fold_inputs(out, lam_dev, plan.has_loss,
-                                 plan.sketch)
-            if poison:
-                # injected kernel pathology: every float statistic of
-                # the chunk turns NaN; the fold guard must quarantine
-                # the points, not the campaign
-                chunk = dict(chunk)
-                chunk["mean_latency"] = (chunk["mean_latency"]
-                                         + np.float32("nan"))
-            acc, summary_ref = fold(acc, chunk,
-                                    np.arange(start,
-                                              start + c_size + pad2,
-                                              dtype=np.int64),
-                                    np.int64(n_valid))
-        if is_ckpt:
+        with metrics.span("campaign.fold", chunk=ci) as sp:
+            lam_dev = engine.pad_tail(plan.params["lam"], pad2)
             with jax.enable_x64(True):
-                ckpt_ref = (jax.tree_util.tree_map(lambda a: a + 0, acc)
-                            if donate else acc)
-        else:
-            ckpt_ref = None
+                fold = _build_fold(c_size + pad2, n_bins, k_top,
+                                   plan.has_loss, plan.sketch, True,
+                                   donate)
+                chunk = _fold_inputs(out, lam_dev, plan.has_loss,
+                                     plan.sketch)
+                if poison:
+                    # injected kernel pathology: every float statistic
+                    # of the chunk turns NaN; the fold guard must
+                    # quarantine the points, not the campaign
+                    chunk = dict(chunk)
+                    chunk["mean_latency"] = (chunk["mean_latency"]
+                                             + np.float32("nan"))
+                acc, summary_ref = fold(acc, chunk,
+                                        np.arange(start,
+                                                  start + c_size + pad2,
+                                                  dtype=np.int64),
+                                        np.int64(n_valid))
+                ckpt_ref = None
+                if is_ckpt:
+                    ckpt_ref = (jax.tree_util.tree_map(
+                        lambda a: a + 0, acc) if donate else acc)
+        host_s += sp.seconds
         pending.append((ci, summary_ref, ckpt_ref,
                         {"start": start, "points": n_valid,
                          "padded": (c_size - n_valid) + pad2,
                          "tapped": bool(tap_this),
                          "retries": attempt,
+                         "host_s": round(host_s, 6),
                          "_grid_bytes": _nbytes(cgrid._arrays())}))
         while len(pending) > max(depth, 1):
             drain_one()
     while pending:
         drain_one()
 
-    acc_np = jax.device_get(acc)
+    with metrics.span("campaign.result"):
+        acc_np = jax.device_get(acc)
     completed = last_chunk == n_chunks
     return CampaignResult(
         kind=kind, mode="pipelined", n_points=n, n_chunks=n_chunks,

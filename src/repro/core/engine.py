@@ -294,7 +294,9 @@ class KernelPlan(NamedTuple):
     ``dispatch_device`` instead, keeping the outputs on device for the
     streaming reduction.  ``sketch``/``has_loss`` record the output
     schema the kernel was compiled with (whether ``hist_sums`` and the
-    loss counters are present)."""
+    loss counters are present).  ``supersteps`` is the number of
+    supersteps one run of the kernel scans, each of ``superstep_len``
+    steps and one histogram update."""
 
     kernel: Callable
     params: Dict[str, Any]
@@ -303,6 +305,8 @@ class KernelPlan(NamedTuple):
     n_dev: int
     sketch: bool
     has_loss: bool
+    supersteps: int
+    superstep_len: int
 
 
 def dispatch_device(kernel: Callable, params: Dict[str, Any], keys,

@@ -758,7 +758,9 @@ def gen_plan(grid: GenGrid, *, n_steps: int = 4096,
     keys = engine.point_keys(seed, key_offset, n)
     return engine.KernelPlan(kernel=kernel, params=params, keys=keys,
                              n=n, n_dev=n_dev, sketch=bool(sketch),
-                             has_loss=has_loss)
+                             has_loss=has_loss,
+                             supersteps=n_steps // _REBASE_EVERY,
+                             superstep_len=_REBASE_EVERY)
 
 
 def gen_sweep(grid: GenGrid, *, n_steps: int = 4096,

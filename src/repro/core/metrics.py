@@ -37,6 +37,26 @@ JSONL schema (one object per line):
 ``wall_s`` is host time since the tap first heard from the dispatch;
 ``jobs_per_sec`` is the incremental rate since the previously flushed
 superstep (null for the first).
+
+Program spans
+-------------
+
+The program's own host spans live here too: ``span(name, **attrs)``
+times a block of host work on ``time.perf_counter_ns`` and, through a
+``jax.profiler.TraceAnnotation`` of the same name, shows it on the host
+plane of any profiler trace taken meanwhile.  Each closed span goes to
+a bounded in-memory ring as ``Span(name, parent, start_ns, end_ns,
+attrs)``, ``parent`` being the innermost span open on the same thread;
+``spans()`` returns a snapshot.  The recorder is always on: a span
+costs a few microseconds of host time, and the hot paths open a
+handful per chunk or batch.
+
+From the first span on, one ``jax.monitoring`` listener also records
+JAX's compile activity as spans that end when JAX reports them:
+``jax.trace`` (tracing to a jaxpr), ``jax.lower`` (jaxpr to MLIR),
+``jax.compile`` (one backend compile, or one load from the persistent
+cache) and ``jax.cache_load`` (the load, nested in its ``jax.compile``):
+the enclosing span says which step compiled.
 """
 from __future__ import annotations
 
@@ -46,9 +66,11 @@ import os
 import tempfile
 import threading
 import time
-from typing import IO, Optional
+from collections import deque
+from typing import IO, Any, Dict, List, NamedTuple, Optional
 
-__all__ = ["MetricsTap", "tap_superstep"]
+__all__ = ["MetricsTap", "tap_superstep", "Span", "SpanLog", "span",
+           "spans"]
 
 # per-lane scalar payload streamed by the kernels, in callback order
 FIELDS = ("queue", "jobs", "busy", "span", "dropped", "overflow",
@@ -210,8 +232,9 @@ class MetricsTap:
 
     def observe_chunk(self, **scalars) -> None:
         """Append a ``chunk`` record — the campaign driver streams one
-        per completed chunk (index, points, pad waste, loss totals,
-        wall time) for mid-flight progress watching.
+        per completed chunk (its ``chunks.jsonl`` row: index, points,
+        pad waste, loss totals, host and wait time) for mid-flight
+        progress watching.
 
         Campaign tap contract: a tapped dispatch forces single-shard
         execution (see the class docstring), so the campaign does NOT
@@ -268,3 +291,129 @@ def tap_superstep(tap: Optional[MetricsTap], step, **vals) -> None:
 
     args = [jnp.asarray(vals.get(f, 0)) for f in FIELDS]
     io_callback(tap._record, None, step, *args, ordered=False)
+
+
+# ---------------------------------------------------------------------------
+# program spans
+# ---------------------------------------------------------------------------
+
+RING = 65536                     # closed spans kept, newest last
+
+# jax.monitoring duration events -> span names
+_JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax.cache_load",
+}
+
+
+class Span(NamedTuple):
+    """One closed span: host times in ``perf_counter_ns``."""
+    name: str
+    parent: Optional[str]
+    start_ns: int
+    end_ns: int
+    attrs: Dict[str, Any]
+
+
+class _Open:
+    """An open span; ``attrs`` may still be added to inside the block,
+    and ``seconds`` is its length once it has closed."""
+
+    __slots__ = ("log", "name", "attrs", "parent", "start_ns", "end_ns",
+                 "_ann")
+
+    def __init__(self, log: "SpanLog", name: str, attrs: dict):
+        self.log, self.name, self.attrs = log, name, attrs
+        self.end_ns = None
+
+    def __enter__(self) -> "_Open":
+        from jax.profiler import TraceAnnotation
+
+        stack = self.log._stack()
+        self.parent = stack[-1] if stack else None
+        stack.append(self.name)
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end_ns = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        self.log._stack().pop()
+        self.log._ring.append(Span(self.name, self.parent, self.start_ns,
+                                   self.end_ns, self.attrs))
+
+    @property
+    def seconds(self) -> float:
+        return (self.end_ns - self.start_ns) * 1e-9
+
+
+class SpanLog:
+    """A bounded ring of closed spans, safe to share between threads.
+    The module keeps one (``span``, ``spans``); a test may make its
+    own."""
+
+    def __init__(self, maxlen: int = RING):
+        self._ring: deque = deque(maxlen=maxlen)
+        self._local = threading.local()
+
+    def _stack(self) -> List[str]:
+        st = getattr(self._local, "stack", None)
+        if st is None:
+            st = self._local.stack = []
+        return st
+
+    def span(self, name: str, **attrs) -> _Open:
+        return _Open(self, name, attrs)
+
+    def record(self, name: str, start_ns: int, end_ns: int,
+               **attrs) -> None:
+        """Add a span that was timed elsewhere, under the innermost
+        span open on this thread."""
+        stack = self._stack()
+        self._ring.append(Span(name, stack[-1] if stack else None,
+                               start_ns, end_ns, attrs))
+
+    def spans(self) -> List[Span]:
+        return list(self._ring)
+
+    def on_jax_duration(self, event: str, seconds: float, **kw) -> None:
+        """``jax.monitoring`` duration listener: JAX reports an event
+        when it ends, so the span ends now."""
+        name = _JAX_EVENTS.get(event)
+        if name is None:
+            return
+        end = time.perf_counter_ns()
+        self.record(name, end - int(seconds * 1e9), end, **kw)
+
+
+_LOG = SpanLog()
+_listening = False
+_listen_lock = threading.Lock()
+
+
+def _listen() -> None:
+    global _listening
+    with _listen_lock:
+        if not _listening:
+            import jax.monitoring
+
+            jax.monitoring.register_event_duration_secs_listener(
+                _LOG.on_jax_duration)
+            _listening = True
+
+
+def span(name: str, **attrs) -> _Open:
+    """Context manager: time the block as span ``name`` with ``attrs``
+    (module docstring).  Yields the open span."""
+    if not _listening:
+        _listen()
+    return _LOG.span(name, **attrs)
+
+
+def spans() -> List[Span]:
+    """Snapshot of the closed spans in the ring, oldest first."""
+    return _LOG.spans()

@@ -677,7 +677,9 @@ def sweep_plan(grid: SweepGrid, *, n_batches: int = 3000,
     keys = engine.point_keys(seed, key_offset, n)
     return engine.KernelPlan(kernel=kernel, params=params, keys=keys,
                              n=n, n_dev=n_dev, sketch=bool(sketch),
-                             has_loss=has_loss)
+                             has_loss=has_loss,
+                             supersteps=n_batches // _REBASE_EVERY,
+                             superstep_len=_REBASE_EVERY)
 
 
 def sweep(grid: SweepGrid, *, n_batches: int = 3000,
@@ -1601,7 +1603,9 @@ def fleet_plan(grid: FleetGrid, *, n_steps: int = 6000,
     keys = engine.point_keys(seed, key_offset, n)
     return engine.KernelPlan(kernel=kernel, params=params, keys=keys,
                              n=n, n_dev=n_dev, sketch=bool(sketch),
-                             has_loss=has_loss)
+                             has_loss=has_loss,
+                             supersteps=n_steps // _REBASE_EVERY,
+                             superstep_len=_REBASE_EVERY)
 
 
 def fleet_sweep(grid: FleetGrid, *, n_steps: int = 6000,

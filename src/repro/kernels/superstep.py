@@ -209,7 +209,7 @@ def _pallas_hist_points(*args, shift: int, base: int, n_bins: int):
         input_output_aliases={2 + i: i for i in range(len(hists))},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=_interpret(),
+        interpret=_interpret(), name="superstep_hist",
     )(lats, inc, *hists)
     return tuple(o[:n] for o in out)
 
@@ -298,7 +298,7 @@ def _pallas_compact_points(buf, k, now):
         out_shape=jax.ShapeDtypeStruct((n_pad, width), buf.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=_interpret(),
+        interpret=_interpret(), name="superstep_compact",
     )(k, now, buf)
     return (out[:n_pts, :n],)
 

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core import metrics
 from repro.core.calibrate import fit_service_model
 from repro.core.policy import BatchAllWaiting, BatchPolicy
 from repro.models import build
@@ -147,13 +148,19 @@ class InferenceEngine:
         """Execute one batch of b requests; return wall seconds.  The
         inputs reach the device before the clock starts, and the clock
         stops when the outputs are ready, so the time is the batch's
-        device service time plus dispatch."""
+        device service time plus dispatch.  Spans: ``engine.batch``
+        over the call, ``engine.prepare`` over the input preparation
+        the clock leaves out, ``engine.run`` over what it times."""
         bb = self.bucket_of(b)
-        batch = jax.block_until_ready(self._make_batch(bb))
-        t0 = time.perf_counter()
-        out = self._fns[bb](self.params, batch)
-        jax.block_until_ready(out)
-        return time.perf_counter() - t0
+        with metrics.span("engine.batch", b=b, bucket=bb):
+            with metrics.span("engine.prepare"):
+                batch = jax.block_until_ready(self._make_batch(bb))
+            with metrics.span("engine.run"):
+                t0 = time.perf_counter()
+                out = self._fns[bb](self.params, batch)
+                jax.block_until_ready(out)
+                t = time.perf_counter() - t0
+        return t
 
     def warmup(self) -> None:
         for b in self.buckets:
