@@ -133,7 +133,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool) -> Dict[str, Any]:
         mesh = make_production_mesh(multi_pod=multi_pod)
         fn, args, shardings = build_lowerable(arch, shape_name, mesh)
         named = shd.to_named(shardings, mesh)
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(fn, in_shardings=named)
             lowered = jitted.lower(*args)
             rec["lower_s"] = round(time.time() - t0, 2)
@@ -180,7 +180,7 @@ def _probe_cfg(cfg, repeats: int):
 def _lower_costs(arch: str, shape_name: str, mesh, cfg) -> Dict[str, Any]:
     fn, args, shardings = build_lowerable(arch, shape_name, mesh, cfg=cfg)
     named = shd.to_named(shardings, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=named).lower(*args).compile()
     cost = compiled.cost_analysis()
     if isinstance(cost, (list, tuple)):

@@ -5,9 +5,11 @@ Conventions
 -----------
 - Full-sequence path (train / prefill): ``apply_attention(... , kv_write=...)``
   returns ``(out, (k, v))`` so the caller can populate a KV cache.
-- Decode path: ``decode_attention`` takes a cache ``{"k","v"}`` of fixed
-  length ``S_max``, per-sequence fill ``lengths (B,)``, writes the new token's
-  K/V at index ``lengths`` and attends over the valid prefix (+ itself).
+- Decode path: ``gqa_decode`` / ``mla_decode`` take a cache of fixed length
+  ``S_max`` (one layer's, or the layer stack's with the layer's index),
+  per-sequence fill ``lengths (B,)``, write the new token's K/V rows at
+  index ``lengths`` (``write_rows``) and attend over the valid prefix
+  (+ itself).
 - Long sequences use a q-block-chunked computation (lax.scan over query
   blocks) so the score matrix never materialises at (S, S) — the pure-JAX
   analogue of the Pallas flash kernel in ``repro.kernels``.
@@ -236,43 +238,45 @@ def gqa_forward(p: Params, cfg: ModelConfig, x: jnp.ndarray,
 
 def gqa_decode(p: Params, cfg: ModelConfig, x: jnp.ndarray,
                cache: Dict[str, jnp.ndarray], lengths: jnp.ndarray, *,
-               window: int = 0, rope: bool = True
+               window: int = 0, rope: bool = True,
+               layer: Optional[jnp.ndarray] = None
                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Single-token decode. x: (B,1,d); cache k/v: (B,S_max,KV,hd)
-    (bf16/f32, or int8 + per-slot scales when kv_quantized())."""
-    b = x.shape[0]
+    (bf16/f32, or int8 + per-slot scales when kv_quantized()), or the
+    layer stack's (R,B,S_max,KV,hd) with this block's index ``layer``.
+    Writes the new token's rows (``write_rows``) and attends over this
+    layer's cache; returns the written k/v leaves (stacked if given so)."""
     q, k_new, v_new = _project_qkv(p, cfg, x, lengths[:, None], rope=rope)
-    t = cache["k"].shape[1]
-    pos_k = jnp.arange(t)[None, :]                      # (1, T)
-    mask = _mask(lengths[:, None], pos_k, causal=True, window=window,
-                 kv_len=None)                           # (B, 1, T)
     if "k_scale" in cache:
         kq, ks = quantize_kv(k_new)
         vq, vs = quantize_kv(v_new)
-        new_cache = {
-            "k": _scatter_time(cache["k"], kq, lengths),
-            "k_scale": _scatter_time(cache["k_scale"], ks, lengths),
-            "v": _scatter_time(cache["v"], vq, lengths),
-            "v_scale": _scatter_time(cache["v_scale"], vs, lengths),
-        }
+        rows = {"k": kq, "k_scale": ks, "v": vq, "v_scale": vs}
+    else:
+        rows = {"k": k_new, "v": v_new}
+    new_cache = {n: write_rows(cache[n], r, lengths, layer)
+                 for n, r in rows.items()}
+    c = {n: layer_view(t, layer) for n, t in new_cache.items()}
+    t = c["k"].shape[1]
+    pos_k = jnp.arange(t)[None, :]                      # (1, T)
+    mask = _mask(lengths[:, None], pos_k, causal=True, window=window,
+                 kv_len=None)                           # (B, 1, T)
+    if "k_scale" in c:
         # dequant-fused dots: scores[t] = (q·k_i8[t])·kscale[t];
         # out = Σ_t (p[t]·vscale[t])·v_i8[t] — scales factor out of the dot
-        kc = new_cache["k"]
+        kc = c["k"]
         sc = _gqa_scores(q, kc.astype(q.dtype))
         kv = kc.shape[2]
         g = q.shape[2] // kv
-        ksc = jnp.repeat(new_cache["k_scale"][..., 0], g, axis=2) \
-            if g > 1 else new_cache["k_scale"][..., 0]
+        ksc = jnp.repeat(c["k_scale"][..., 0], g, axis=2) \
+            if g > 1 else c["k_scale"][..., 0]
         sc = sc * ksc.transpose(0, 2, 1)[:, None, :, :]
         pattn = _masked_softmax(sc, mask[:, :, None, :])
-        vsc = jnp.repeat(new_cache["v_scale"][..., 0], g, axis=2) \
-            if g > 1 else new_cache["v_scale"][..., 0]
+        vsc = jnp.repeat(c["v_scale"][..., 0], g, axis=2) \
+            if g > 1 else c["v_scale"][..., 0]
         pattn = pattn * vsc.transpose(0, 2, 1)[:, None, :, :]
-        out = _gqa_out(pattn, new_cache["v"].astype(q.dtype)).astype(q.dtype)
+        out = _gqa_out(pattn, c["v"].astype(q.dtype)).astype(q.dtype)
     else:
-        new_cache = {"k": _scatter_time(cache["k"], k_new, lengths),
-                     "v": _scatter_time(cache["v"], v_new, lengths)}
-        out = sdpa(q, new_cache["k"], new_cache["v"], mask)
+        out = sdpa(q, c["k"], c["v"], mask)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, new_cache
 
@@ -291,14 +295,55 @@ def quantize_kv(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return q.astype(jnp.int8), scale.astype(jnp.float32)
 
 
+def time_axis_may_be_sharded() -> bool:
+    """True when the trace runs under a mesh of more than one device
+    (``jax.set_mesh``): there the decode cache's time axis may be split
+    over devices (``launch.sharding.cache_specs`` puts it on "model")."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return not mesh.empty and mesh.size > 1
+
+
+def layer_view(cache: jnp.ndarray,
+               layer: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """Layer ``layer`` of a stacked (R,B,S,...) cache leaf; the leaf
+    itself when ``layer`` is None."""
+    if layer is None:
+        return cache
+    return jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+
+
+def write_rows(cache: jnp.ndarray, new: jnp.ndarray, lengths: jnp.ndarray,
+               layer: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """Write new (B,1,...) into cache (B,S,...) at time lengths[b] of row
+    b, or into layer ``layer`` of a stacked (R,B,S,...) cache.
+
+    Where the time axis is whole on the device this is a scatter of the B
+    rows, in place on a loop-carried cache: the rest of the cache is
+    neither read nor copied. A row whose length is past the end is not
+    written. Where a mesh may shard the time axis, a layer's own cache
+    (``layer`` None) is rewritten by ``_scatter_time``'s mask-select."""
+    if time_axis_may_be_sharded():
+        return _scatter_time(cache, new, lengths)
+    rows = jnp.arange(new.shape[0])
+    idx = (rows, lengths) if layer is None else (layer, rows, lengths)
+    return cache.at[idx].set(new[:, 0].astype(cache.dtype), mode="drop")
+
+
 def _scatter_time(cache: jnp.ndarray, new: jnp.ndarray,
                   lengths: jnp.ndarray) -> jnp.ndarray:
-    """Write new (B,1,...) into cache (B,S,...) at per-row index lengths.
+    """Write new (B,1,...) into cache (B,S,...) at per-row index lengths,
+    as a mask-select over every position of the cache.
 
-    Formulated as a mask-select so it partitions cleanly when the cache is
-    sequence-sharded (§Perf D1). A vmapped dynamic_update_slice was tried
-    (§Perf D2) and REFUTED: GSPMD turns the dynamic index on the sharded
-    dim into all-gathers (bytes 6.4e10 → 1.25e11 on qwen4b decode_32k).
+    Used only where the time axis may be sharded over a mesh
+    (``write_rows``, ``time_axis_may_be_sharded``): a mask-select
+    partitions cleanly when the cache is sequence-sharded (§Perf D1). A
+    vmapped dynamic_update_slice was tried there (§Perf D2) and REFUTED:
+    GSPMD turns the dynamic index on the sharded dim into all-gathers
+    (bytes 6.4e10 → 1.25e11 on qwen4b decode_32k; the row scatter on
+    qwen1.5-0.5b decode_32k: bytes 1.13e10 → 2.31e10, all-gathers
+    9.8e4 → 1.15e6 B, 16x16 dry run). On one device it costs a read and a
+    write of the whole layer cache per step, which the in-place row write
+    avoids.
     """
     t = cache.shape[1]
     mask = (jnp.arange(t)[None, :] == lengths[:, None])      # (B, S)
@@ -379,11 +424,12 @@ def mla_forward(p: Params, cfg: ModelConfig, x: jnp.ndarray,
 
 def mla_decode(p: Params, cfg: ModelConfig, x: jnp.ndarray,
                cache: Dict[str, jnp.ndarray], lengths: jnp.ndarray, *,
-               window: int = 0
+               window: int = 0, layer: Optional[jnp.ndarray] = None
                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Absorbed-form MLA decode: attention runs in the latent space.
 
-    cache: {"c_kv": (B,S,rank), "k_pe": (B,S,rope)}.
+    cache: {"c_kv": (B,S,rank), "k_pe": (B,S,rope)}, or the layer stack's
+    (R,B,S,·) with this block's index ``layer`` (as ``gqa_decode``).
     score[h,t] = q_nope[h]·(W_uk[h] c_kv[t]) + q_pe[h]·k_pe[t]
                = (q_nope[h] W_uk[h]) · c_kv[t] + q_pe[h]·k_pe[t]
     out[h]     = Σ_t p[t] (W_uv[h] c_kv[t]) = W_uv[h] (Σ_t p[t] c_kv[t]).
@@ -391,8 +437,10 @@ def mla_decode(p: Params, cfg: ModelConfig, x: jnp.ndarray,
     m = cfg.mla
     q_nope, q_pe = _mla_q(p, cfg, x, lengths[:, None])
     c_new, kpe_new = _mla_latent(p, cfg, x, lengths[:, None])
-    c_cache = _scatter_time(cache["c_kv"], c_new, lengths)
-    kpe_cache = _scatter_time(cache["k_pe"], kpe_new, lengths)
+    new_cache = {"c_kv": write_rows(cache["c_kv"], c_new, lengths, layer),
+                 "k_pe": write_rows(cache["k_pe"], kpe_new, lengths, layer)}
+    c_cache = layer_view(new_cache["c_kv"], layer)
+    kpe_cache = layer_view(new_cache["k_pe"], layer)
     # absorb W_uk into q:  (B,1,H,nope) x (rank,H,nope) -> (B,1,H,rank)
     q_abs = jnp.einsum("bshk,rhk->bshr", q_nope.astype(jnp.float32),
                        p["w_uk"].astype(jnp.float32))
@@ -409,4 +457,4 @@ def mla_decode(p: Params, cfg: ModelConfig, x: jnp.ndarray,
     out = jnp.einsum("bshr,rhk->bshk", ctx,
                      p["w_uv"].astype(jnp.float32)).astype(x.dtype)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
-    return y, {"c_kv": c_cache, "k_pe": kpe_cache}
+    return y, new_cache

@@ -136,9 +136,14 @@ def apply_block(cfg: ModelConfig, bp: Params, kind: str, moe_flag: bool,
                 lengths: Optional[jnp.ndarray] = None,
                 cache: Optional[Params] = None,
                 cache_len: int = 0, window: int = 0, causal: bool = True,
-                cross_enc: Optional[jnp.ndarray] = None
+                cross_enc: Optional[jnp.ndarray] = None,
+                layer: Optional[jnp.ndarray] = None
                 ) -> Tuple[jnp.ndarray, Optional[Params], jnp.ndarray]:
-    """Apply one block. mode: 'full' | 'prefill' | 'decode'."""
+    """Apply one block. mode: 'full' | 'prefill' | 'decode'.
+
+    In decode, ``cache`` is this block's cache, or with ``layer`` given
+    the layer stack's, whose leaves lead with the layer axis; the block
+    writes its own rows and state into it and returns it."""
     aux = jnp.zeros((), jnp.float32)
     new_cache: Optional[Params] = None
     h = apply_norm(bp["norm1"], x, cfg.norm)
@@ -146,13 +151,12 @@ def apply_block(cfg: ModelConfig, bp: Params, kind: str, moe_flag: bool,
     if kind == "attn":
         if mode == "decode":
             if cfg.mla is not None:
-                a, kv = attn.mla_decode(bp["attn"], cfg, h,
-                                        {"c_kv": cache["c_kv"],
-                                         "k_pe": cache["k_pe"]},
-                                        lengths, window=window)
+                a, kv = attn.mla_decode(bp["attn"], cfg, h, cache, lengths,
+                                        window=window, layer=layer)
             else:
                 a, kv = attn.gqa_decode(bp["attn"], cfg, h, cache,
-                                        lengths, window=window, rope=rope)
+                                        lengths, window=window, rope=rope,
+                                        layer=layer)
             new_cache = dict(cache)
             new_cache.update(kv)
         else:
@@ -182,21 +186,23 @@ def apply_block(cfg: ModelConfig, bp: Params, kind: str, moe_flag: bool,
         x = x + a
         if "xattn" in bp:
             hx = apply_norm(bp["norm_x"], x, cfg.norm)
-            if mode == "decode":
-                ck, cv = cache["cross_k"], cache["cross_v"]
+            if mode == "decode":       # read-only: stays in the cache
+                ck = attn.layer_view(cache["cross_k"], layer)
+                cv = attn.layer_view(cache["cross_v"], layer)
             else:
                 ck, cv = attn.cross_kv(bp["xattn"], cross_enc)
                 if mode == "prefill":
                     new_cache["cross_k"] = ck
                     new_cache["cross_v"] = cv
             x = x + attn.cross_attend(bp["xattn"], hx, ck, cv)
-            if mode == "decode":
-                new_cache["cross_k"] = ck
-                new_cache["cross_v"] = cv
     else:
-        if mode == "decode":
-            a, new_cache = ssm.mamba2_decode(bp["ssm"], cfg.d_model, cfg.ssm,
-                                             h, cache)
+        if mode == "decode":            # small state: rewritten whole
+            a, st = ssm.mamba2_decode(
+                bp["ssm"], cfg.d_model, cfg.ssm, h,
+                jax.tree.map(lambda c: attn.layer_view(c, layer), cache))
+            new_cache = st if layer is None else jax.tree.map(
+                lambda c, n: jax.lax.dynamic_update_index_in_dim(
+                    c, n, layer, 0), cache, st)
         else:
             a, sc = ssm.mamba2_forward(bp["ssm"], cfg.d_model, cfg.ssm, h)
             if mode == "prefill":
@@ -383,34 +389,40 @@ def _run_stack(cfg: ModelConfig, params: Params, x: jnp.ndarray, *,
         new_cache["lead"].append(nc)
 
     offsets = [specs[lead + j] for j in range(p)]
-    with_cache = mode in ("prefill", "decode")
+    # Decode on a device that holds the cache's time axis whole carries the
+    # stacked cache through the layer scan: each block writes its new rows
+    # in place at its layer index, so no per-step op stacks, selects over
+    # or copies a whole cache. Where a mesh may shard that axis, decode
+    # reads the cache as xs and emits each layer's mask-selected cache as
+    # ys, as prefill emits its cache (§Perf D2, attention.write_rows).
+    carried = mode == "decode" and not attn.time_axis_may_be_sharded()
 
     def body(carry, xs):
-        h = carry
-        bps = xs[0]
-        cs = xs[1] if with_cache and mode == "decode" else [None] * p
-        ncs = []
+        h, stack = carry
+        bps, i, cs = xs
+        cs = list(stack if carried else cs)
         aux = jnp.zeros((), jnp.float32)
         for j in range(p):
             kind, mf = offsets[j]
             h = _shard_seq(h)
-            h, nc, a = apply_block(
+            h, cs[j], a = apply_block(
                 cfg, bps[j], kind, mf, h, mode=mode, positions=positions,
                 lengths=lengths, cache=cs[j], cache_len=cache_len,
-                window=window, cross_enc=cross_enc)
+                window=window, cross_enc=cross_enc,
+                layer=i if carried else None)
             aux += a
-            ncs.append(nc)
-        out = (tuple(ncs), aux) if with_cache else aux
-        return h, out
+        out = aux if carried or mode == "full" else (tuple(cs), aux)
+        return (h, tuple(cs) if carried else None), out
 
     if remat:
         body = jax.checkpoint(body)
 
-    xs = (tuple(params["stack"]),)
-    if with_cache and mode == "decode":
-        xs = xs + (tuple(cache["stack"]),)
+    xs_cache = (tuple(cache["stack"]) if mode == "decode" and not carried
+                else (None,) * p)
+    xs = (tuple(params["stack"]), jnp.arange(r), xs_cache)
+    carry = (x, tuple(cache["stack"]) if carried else None)
 
-    group = _remat_group(r) if (remat and not with_cache) else 1
+    group = _remat_group(r) if (remat and mode == "full") else 1
     if group > 1:
         # §Perf P2 (√L remat): outer scan over R/g checkpointed groups,
         # inner scan over g layer-periods — saved residuals drop from R·x
@@ -419,19 +431,23 @@ def _run_stack(cfg: ModelConfig, params: Params, x: jnp.ndarray, *,
             lambda t: t.reshape((r // group, group) + t.shape[1:]), xs)
 
         @jax.checkpoint
-        def outer(h, xsg):
-            return jax.lax.scan(body, h, xsg, unroll=_scan_unroll())
+        def outer(c, xsg):
+            return jax.lax.scan(body, c, xsg, unroll=_scan_unroll())
 
-        x, ys = jax.lax.scan(outer, x, xs_g, unroll=_scan_unroll())
+        (x, stack), ys = jax.lax.scan(outer, carry, xs_g,
+                                      unroll=_scan_unroll())
         ys = jax.tree.map(lambda t: t.reshape((r,) + t.shape[2:]), ys)
     else:
-        x, ys = jax.lax.scan(body, x, xs, unroll=_scan_unroll())
-    if with_cache:
-        new_cache["stack"] = list(ys[0])
-        aux_total += jnp.sum(ys[1])
-    else:
+        (x, stack), ys = jax.lax.scan(body, carry, xs, unroll=_scan_unroll())
+    if carried:
+        new_cache["stack"] = list(stack)
+        aux_total += jnp.sum(ys)
+    elif mode == "full":
         new_cache = None
         aux_total += jnp.sum(ys)
+    else:
+        new_cache["stack"] = list(ys[0])
+        aux_total += jnp.sum(ys[1])
     return x, new_cache, aux_total
 
 

@@ -100,6 +100,45 @@ def test_prefill_decode_matches_forward(arch, rigs):
 
 
 @pytest.mark.parametrize("arch", ARCHS)
+def test_prefill_decode_matches_forward_unequal_lengths(arch, rigs):
+    """Rows of unequal length in one decode batch (as the continuous
+    engine's slot pool has them): each row prefilled alone with its own
+    prompt length into one cache, then decoded together; every row's
+    logits match the full-sequence reference at its own position."""
+    cfg, bundle, params = rigs[arch]
+    lens, extra = (20, 32, 27), 3
+    b, total = len(lens), max(lens) + extra
+    full = _batch(cfg, jax.random.PRNGKey(7), b, total)
+    toks = full["tokens"]
+    offset = cfg.encoder.n_ctx if cfg.family == "vlm" else 0
+    ref, _ = bundle.forward(params, full)
+    prefill = jax.jit(bundle.prefill, static_argnums=2)
+    decode = jax.jit(bundle.decode_step)
+    rows = []
+    for i, n in enumerate(lens):
+        pre = {k: v[i:i + 1] for k, v in full.items()}
+        pre["tokens"] = toks[i:i + 1, :n]
+        lg, c = prefill(params, pre, total + offset)
+        np.testing.assert_allclose(np.asarray(lg[0, 0]),
+                                   np.asarray(ref[i, n - 1 + offset]),
+                                   rtol=3e-4, atol=3e-4)
+        rows.append(c)
+    cache = {"lead": jax.tree.map(lambda *x: jnp.concatenate(x, 0),
+                                  *[c["lead"] for c in rows]),
+             "stack": jax.tree.map(lambda *x: jnp.concatenate(x, 1),
+                                   *[c["stack"] for c in rows])}
+    lengths = jnp.asarray(lens, jnp.int32) + offset
+    for t in range(extra):
+        idx = jnp.asarray(lens) + t
+        tok = jnp.take_along_axis(toks, idx[:, None], axis=1)
+        lg, cache = decode(params, tok, cache, lengths)
+        want = ref[jnp.arange(b), idx + offset]
+        np.testing.assert_allclose(np.asarray(lg[:, 0]), np.asarray(want),
+                                   rtol=3e-4, atol=3e-4)
+        lengths = lengths + 1
+
+
+@pytest.mark.parametrize("arch", ARCHS)
 def test_sliding_window_decode(arch, rigs):
     """Windowed decode runs and, for attention archs, differs from full
     attention when the context exceeds the window."""
