@@ -19,7 +19,12 @@ from typing import Dict, List, Optional, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
-    """Mixture-of-experts block configuration."""
+    """Mixture-of-experts block configuration.
+
+    ``num_experts`` is the published count, which the router scores.  A
+    layer that holds a share of them (expert parallelism) holds the
+    contiguous range ``[expert_offset, expert_offset + held_experts)``;
+    ``held_experts`` 0 means all of them."""
 
     num_experts: int
     top_k: int
@@ -30,6 +35,11 @@ class MoEConfig:
     moe_layer_period: int = 1          # MoE every k-th layer (Jamba: 2)
     first_dense: int = 0               # leading dense layers (DeepSeek-V2: 1)
     capacity_factor: float = 1.25      # expert capacity slack (GShard)
+    held_experts: int = 0              # routed experts held here (0: all)
+    expert_offset: int = 0             # first held expert
+
+    def held(self) -> int:
+        return self.held_experts or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -48,6 +58,20 @@ class SSMConfig:
 
     def n_heads(self, d_model: int) -> int:
         return self.d_inner(d_model) // self.head_dim
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rotary scaling (``rope_scaling`` of type ``yarn`` in a Hugging
+    Face config): frequencies interpolated between the original and the
+    extended context, and an attention-temperature factor ``mscale``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -104,6 +128,7 @@ class ModelConfig:
     activation: str = "swiglu"         # 'swiglu' | 'gelu'
     norm: str = "rmsnorm"              # 'rmsnorm' | 'layernorm'
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None
     partial_rotary_factor: float = 1.0
     max_position_embeddings: int = 32768
     tie_embeddings: bool = False
@@ -156,11 +181,14 @@ class ModelConfig:
 
     # --- parameter counting (for roofline MODEL_FLOPS = 6·N·D) ----------
     def param_counts(self) -> Dict[str, float]:
-        """Return {'total': N, 'active': N_active} parameter counts."""
+        """Return {'total': N, 'active': N_active, 'published': N_pub}:
+        ``total`` counts the routed experts held here, ``published`` all
+        of them (the two differ where a layer holds a share)."""
         d, L = self.d_model, self.num_layers
         emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
         total = float(emb)
         active = float(emb)
+        published = float(emb)
         kinds = self.layer_kinds()
         moe_flags = self.moe_layers()
         for i in range(L):
@@ -175,6 +203,7 @@ class ModelConfig:
                 blk += 3 * nh  # A, D, dt_bias
                 total += blk
                 active += blk
+                published += blk
             else:
                 if self.mla is not None:
                     m = self.mla
@@ -190,26 +219,29 @@ class ModelConfig:
                     a += self.num_heads * hd * d
                 total += a
                 active += a
+                published += a
             # FFN
             mult = 3 if self.activation == "swiglu" else 2
             if moe_flags[i]:
                 mo = self.moe
-                routed = mo.num_experts * mult * d * mo.d_expert
+                expert = mult * d * mo.d_expert
                 shared = mo.num_shared_experts * mult * d * mo.d_shared
                 router = d * mo.num_experts
-                total += routed + shared + router
-                active += (mo.top_k * mult * d * mo.d_expert
-                           + shared + router)
+                total += mo.held() * expert + shared + router
+                published += mo.num_experts * expert + shared + router
+                active += mo.top_k * expert + shared + router
             elif self.d_ff:
                 total += mult * d * self.d_ff
                 active += mult * d * self.d_ff
+                published += mult * d * self.d_ff
         if self.encoder is not None and self.encoder.num_layers:
             e = self.encoder
             ed = e.d_model or d
             per = 4 * ed * ed + 2 * ed * (e.d_ff or 4 * ed)
             total += e.num_layers * per
             active += e.num_layers * per
-        return {"total": total, "active": active}
+            published += e.num_layers * per
+        return {"total": total, "active": active, "published": published}
 
 
 # ---------------------------------------------------------------------------
@@ -315,7 +347,6 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
             d_expert=min(cfg.moe.d_expert, 128),
             d_shared=min(cfg.moe.d_shared, 128) if cfg.moe.d_shared else 0,
             first_dense=min(cfg.moe.first_dense, 1),
-            capacity_factor=float(ne) / tk,   # no token drops in smoke tests
         )
     if cfg.ssm is not None:
         kw["ssm"] = replace(cfg.ssm, d_state=16, head_dim=32, chunk_size=32)

@@ -25,7 +25,7 @@ from jax.sharding import PartitionSpec
 
 from repro.configs.base import MLAConfig, ModelConfig
 from repro.models.layers import _init_w, apply_norm
-from repro.models.rope import apply_rope
+from repro.models.rope import apply_rope, yarn_mscale
 
 Params = Dict[str, jnp.ndarray]
 
@@ -88,8 +88,10 @@ def init_mla(key, cfg: ModelConfig, dtype) -> Params:
 # core scaled-dot-product with GQA grouping
 # ---------------------------------------------------------------------------
 
-def _gqa_scores(q: jnp.ndarray, k: jnp.ndarray) -> jnp.ndarray:
-    """q: (B,S,H,hd), k: (B,T,KV,hd) -> scores (B,S,H,T) in f32.
+def _gqa_scores(q: jnp.ndarray, k: jnp.ndarray,
+                scale: Optional[float] = None) -> jnp.ndarray:
+    """q: (B,S,H,hd), k: (B,T,KV,hd) -> scores (B,S,H,T) in f32, times
+    ``scale`` (default hd^-½).
 
     Low-precision operands feed the dot directly (MXU-native bf16 with f32
     accumulation via preferred_element_type) — §Perf D3: an explicit
@@ -102,7 +104,8 @@ def _gqa_scores(q: jnp.ndarray, k: jnp.ndarray) -> jnp.ndarray:
     qr = q.reshape(b, s, kv, g, hd)
     sc = jnp.einsum("bskgh,btkh->bskgt", qr, k,
                     preferred_element_type=jnp.float32)
-    return sc.reshape(b, s, h, k.shape[1]) * (hd ** -0.5)
+    return sc.reshape(b, s, h, k.shape[1]) * (
+        hd ** -0.5 if scale is None else scale)
 
 
 def _gqa_out(p_attn: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
@@ -141,16 +144,17 @@ def _mask(pos_q: jnp.ndarray, pos_k: jnp.ndarray, *, causal: bool,
     return m
 
 
-def sdpa(q, k, v, mask) -> jnp.ndarray:
+def sdpa(q, k, v, mask, scale: Optional[float] = None) -> jnp.ndarray:
     """Full (non-chunked) masked attention. mask broadcast to (B,S,1,T)."""
-    scores = _gqa_scores(q, k)
+    scores = _gqa_scores(q, k, scale)
     p = _masked_softmax(scores, mask[..., :, None, :]
                         if mask.ndim == q.ndim - 1 else mask)
     return _gqa_out(p, v).astype(q.dtype)
 
 
 def chunked_sdpa(q, k, v, pos_q, pos_k, *, causal: bool, window: int,
-                 q_chunk: int = Q_CHUNK) -> jnp.ndarray:
+                 q_chunk: int = Q_CHUNK,
+                 scale: Optional[float] = None) -> jnp.ndarray:
     """Query-block-chunked attention: score matrix is (chunk, T) at a time.
 
     pos_q/pos_k must be 1-D (shared across batch) for this path.
@@ -165,7 +169,7 @@ def chunked_sdpa(q, k, v, pos_q, pos_k, *, causal: bool, window: int,
     def body(_, xs):
         qc, pq = xs
         mask = _mask(pq, pos_k, causal=causal, window=window, kv_len=None)
-        out = sdpa(qc, k, v, mask[None])
+        out = sdpa(qc, k, v, mask[None], scale)
         return None, out
 
     _, outs = jax.lax.scan(body, None, (qs, pqs))
@@ -203,8 +207,10 @@ def _project_qkv(p: Params, cfg: ModelConfig, x, positions, *,
         q = apply_norm({"scale": p["q_norm"]}, q, "rmsnorm")
         k = apply_norm({"scale": p["k_norm"]}, k, "rmsnorm")
     if rope:
-        q = apply_rope(q, positions, cfg.rope_theta, cfg.partial_rotary_factor)
-        k = apply_rope(k, positions, cfg.rope_theta, cfg.partial_rotary_factor)
+        q = apply_rope(q, positions, cfg.rope_theta,
+                       cfg.partial_rotary_factor, cfg.rope_scaling)
+        k = apply_rope(k, positions, cfg.rope_theta,
+                       cfg.partial_rotary_factor, cfg.rope_scaling)
     return q, k, v
 
 
@@ -378,11 +384,22 @@ def cross_attend(p: Params, x: jnp.ndarray, k: jnp.ndarray,
 # MLA (DeepSeek-V2 latent attention)
 # ---------------------------------------------------------------------------
 
+def mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale of MLA: (qk_nope + qk_rope)^-½, times mscale² of
+    ``mscale_all_dim`` under YaRN (DeepSeek-V2's published attention)."""
+    m, rs = cfg.mla, cfg.rope_scaling
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_q(p: Params, cfg: ModelConfig, x, positions):
     m = cfg.mla
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope = q[..., : m.qk_nope_head_dim]
-    q_pe = apply_rope(q[..., m.qk_nope_head_dim:], positions, cfg.rope_theta)
+    q_pe = apply_rope(q[..., m.qk_nope_head_dim:], positions, cfg.rope_theta,
+                      scaling=cfg.rope_scaling)
     return q_nope, q_pe
 
 
@@ -390,7 +407,8 @@ def _mla_latent(p: Params, cfg: ModelConfig, x, positions):
     c_kv = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"])
     c_kv = apply_norm({"scale": p["norm_ckv"]}, c_kv, "rmsnorm")
     k_pe = jnp.einsum("bsd,dr->bsr", x, p["w_kpe"])[:, :, None, :]
-    k_pe = apply_rope(k_pe, positions, cfg.rope_theta)[:, :, 0, :]
+    k_pe = apply_rope(k_pe, positions, cfg.rope_theta,
+                      scaling=cfg.rope_scaling)[:, :, 0, :]
     return c_kv, k_pe
 
 
@@ -411,13 +429,14 @@ def mla_forward(p: Params, cfg: ModelConfig, x: jnp.ndarray,
     q = jnp.concatenate([q_nope, q_pe], axis=-1)
     k = jnp.concatenate([k_nope, k_pe_h], axis=-1)
     s = x.shape[1]
+    scale = mla_scale(cfg)
     if s > CHUNK_THRESHOLD and positions.ndim == 1:
         out = chunked_sdpa(q, k, v, positions, positions, causal=causal,
-                           window=window)
+                           window=window, scale=scale)
     else:
         mask = _mask(positions, positions, causal=causal, window=window,
                      kv_len=None)
-        out = sdpa(q, k, v, mask[None])
+        out = sdpa(q, k, v, mask[None], scale)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, (c_kv, k_pe)
 
@@ -434,7 +453,6 @@ def mla_decode(p: Params, cfg: ModelConfig, x: jnp.ndarray,
                = (q_nope[h] W_uk[h]) · c_kv[t] + q_pe[h]·k_pe[t]
     out[h]     = Σ_t p[t] (W_uv[h] c_kv[t]) = W_uv[h] (Σ_t p[t] c_kv[t]).
     """
-    m = cfg.mla
     q_nope, q_pe = _mla_q(p, cfg, x, lengths[:, None])
     c_new, kpe_new = _mla_latent(p, cfg, x, lengths[:, None])
     new_cache = {"c_kv": write_rows(cache["c_kv"], c_new, lengths, layer),
@@ -448,7 +466,7 @@ def mla_decode(p: Params, cfg: ModelConfig, x: jnp.ndarray,
                     c_cache.astype(jnp.float32))
     sc += jnp.einsum("bshk,btk->bsht", q_pe.astype(jnp.float32),
                      kpe_cache.astype(jnp.float32))
-    sc *= (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    sc *= mla_scale(cfg)
     t = c_cache.shape[1]
     mask = _mask(lengths[:, None], jnp.arange(t)[None, :], causal=True,
                  window=window, kv_len=None)             # (B,1,T)

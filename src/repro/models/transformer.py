@@ -11,6 +11,8 @@ Public API (used by registry / launch / serving):
     prefill(cfg, params, batch, cache_len, window=0) -> (logits, cache)
     decode_step(cfg, params, tokens, cache, lengths, window=0)
                                                -> (logits, cache)
+    (``expert_slots=True``: + the slots each held expert received, per
+    MoE layer)
     init_cache(cfg, batch, cache_len)          -> cache pytree
 """
 from __future__ import annotations
@@ -138,13 +140,16 @@ def apply_block(cfg: ModelConfig, bp: Params, kind: str, moe_flag: bool,
                 cache_len: int = 0, window: int = 0, causal: bool = True,
                 cross_enc: Optional[jnp.ndarray] = None,
                 layer: Optional[jnp.ndarray] = None
-                ) -> Tuple[jnp.ndarray, Optional[Params], jnp.ndarray]:
-    """Apply one block. mode: 'full' | 'prefill' | 'decode'.
+                ) -> Tuple[jnp.ndarray, Optional[Params], jnp.ndarray,
+                           Optional[jnp.ndarray]]:
+    """Apply one block. mode: 'full' | 'prefill' | 'decode'. Returns (x,
+    cache, aux loss, slots each held expert received or None).
 
     In decode, ``cache`` is this block's cache, or with ``layer`` given
     the layer stack's, whose leaves lead with the layer axis; the block
     writes its own rows and state into it and returns it."""
     aux = jnp.zeros((), jnp.float32)
+    slots = None
     new_cache: Optional[Params] = None
     h = apply_norm(bp["norm1"], x, cfg.norm)
     rope = not cfg.learned_positions
@@ -211,11 +216,11 @@ def apply_block(cfg: ModelConfig, bp: Params, kind: str, moe_flag: bool,
     if "ffn" in bp:
         h2 = apply_norm(bp["norm2"], x, cfg.norm)
         if moe_flag:
-            f, aux = apply_moe(bp["ffn"], cfg.moe, h2, cfg.activation)
+            f, aux, slots = apply_moe(bp["ffn"], cfg.moe, h2, cfg.activation)
         else:
             f = apply_mlp(bp["ffn"], h2, cfg.activation)
         x = x + f
-    return x, new_cache, aux
+    return x, new_cache, aux, slots
 
 
 # ---------------------------------------------------------------------------
@@ -356,8 +361,8 @@ def encode(cfg: ModelConfig, params: Params, frames: jnp.ndarray,
     positions = jnp.arange(frames.shape[1])
 
     def body(h, bp):
-        h, _, _ = apply_block(ecfg, bp, "attn", False, h, mode="full",
-                              positions=positions, causal=False)
+        h, _, _, _ = apply_block(ecfg, bp, "attn", False, h, mode="full",
+                                 positions=positions, causal=False)
         return h, None
 
     if remat:  # §Perf W1: un-remat'd encoder kept 24L of activations live
@@ -374,19 +379,25 @@ def _run_stack(cfg: ModelConfig, params: Params, x: jnp.ndarray, *,
                mode: str, positions=None, lengths=None, cache=None,
                cache_len: int = 0, window: int = 0, cross_enc=None,
                remat: bool = False):
+    """Returns (x, cache, aux loss, slots): ``slots`` (MoE layers, held)
+    int32 counts the slots each held expert received, layer by layer
+    (None without MoE layers)."""
     lead, p, r = split_pattern(cfg)
     specs = layer_specs(cfg)
     aux_total = jnp.zeros((), jnp.float32)
     new_cache: Params = {"lead": [], "stack": []}
+    slots = []
 
     for i in range(lead):
         c = cache["lead"][i] if cache is not None else None
-        x, nc, aux = apply_block(
+        x, nc, aux, sl = apply_block(
             cfg, params["lead"][i], specs[i][0], specs[i][1], x, mode=mode,
             positions=positions, lengths=lengths, cache=c,
             cache_len=cache_len, window=window, cross_enc=cross_enc)
         aux_total += aux
         new_cache["lead"].append(nc)
+        if sl is not None:
+            slots.append(sl[None])
 
     offsets = [specs[lead + j] for j in range(p)]
     # Decode on a device that holds the cache's time axis whole carries the
@@ -402,16 +413,20 @@ def _run_stack(cfg: ModelConfig, params: Params, x: jnp.ndarray, *,
         bps, i, cs = xs
         cs = list(stack if carried else cs)
         aux = jnp.zeros((), jnp.float32)
+        sls = []
         for j in range(p):
             kind, mf = offsets[j]
             h = _shard_seq(h)
-            h, cs[j], a = apply_block(
+            h, cs[j], a, sl = apply_block(
                 cfg, bps[j], kind, mf, h, mode=mode, positions=positions,
                 lengths=lengths, cache=cs[j], cache_len=cache_len,
                 window=window, cross_enc=cross_enc,
                 layer=i if carried else None)
             aux += a
-        out = aux if carried or mode == "full" else (tuple(cs), aux)
+            if sl is not None:
+                sls.append(sl)
+        stats = (aux, tuple(sls))
+        out = stats if carried or mode == "full" else (tuple(cs), stats)
         return (h, tuple(cs) if carried else None), out
 
     if remat:
@@ -441,14 +456,19 @@ def _run_stack(cfg: ModelConfig, params: Params, x: jnp.ndarray, *,
         (x, stack), ys = jax.lax.scan(body, carry, xs, unroll=_scan_unroll())
     if carried:
         new_cache["stack"] = list(stack)
-        aux_total += jnp.sum(ys)
+        stats = ys
     elif mode == "full":
         new_cache = None
-        aux_total += jnp.sum(ys)
+        stats = ys
     else:
         new_cache["stack"] = list(ys[0])
-        aux_total += jnp.sum(ys[1])
-    return x, new_cache, aux_total
+        stats = ys[1]
+    aux_total += jnp.sum(stats[0])
+    if stats[1]:                # (repeats, MoE blocks a period, held)
+        st = jnp.stack(stats[1], axis=1)
+        slots.append(st.reshape((-1,) + st.shape[2:]))
+    slots = jnp.concatenate(slots) if slots else None
+    return x, new_cache, aux_total, slots
 
 
 def _embed_in(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
@@ -505,16 +525,17 @@ def forward(cfg: ModelConfig, params: Params, batch: Dict[str, jnp.ndarray],
     else:
         positions = jnp.arange(s)
         x = _embed_in(cfg, params, tokens, positions)
-    x, _, aux = _run_stack(cfg, params, x, mode="full", positions=positions,
-                           window=window, cross_enc=cross_enc, remat=remat)
+    x, _, aux, _ = _run_stack(cfg, params, x, mode="full",
+                              positions=positions, window=window,
+                              cross_enc=cross_enc, remat=remat)
     if _return_hidden:
         return x, aux
     return _logits(cfg, params, x), aux
 
 
 def prefill(cfg: ModelConfig, params: Params, batch: Dict[str, jnp.ndarray],
-            cache_len: int, *, window: int = 0
-            ) -> Tuple[jnp.ndarray, Params]:
+            cache_len: int, *, window: int = 0, expert_slots: bool = False
+            ) -> Tuple:
     tokens = batch["tokens"]
     s = tokens.shape[1]
     cross_enc = None
@@ -526,20 +547,24 @@ def prefill(cfg: ModelConfig, params: Params, batch: Dict[str, jnp.ndarray],
         pe = batch["patch_embeds"]
         positions = jnp.arange(pe.shape[1] + s)
         x = jnp.concatenate([pe.astype(x.dtype), x], axis=1)
-    x, cache, _ = _run_stack(cfg, params, x, mode="prefill",
-                             positions=positions, cache_len=cache_len,
-                             window=window, cross_enc=cross_enc)
-    return _logits(cfg, params, x[:, -1:]), cache
+    x, cache, _, slots = _run_stack(cfg, params, x, mode="prefill",
+                                    positions=positions, cache_len=cache_len,
+                                    window=window, cross_enc=cross_enc)
+    logits = _logits(cfg, params, x[:, -1:])
+    return (logits, cache, slots) if expert_slots else (logits, cache)
 
 
 def decode_step(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
-                cache: Params, lengths: jnp.ndarray, *, window: int = 0
-                ) -> Tuple[jnp.ndarray, Params]:
+                cache: Params, lengths: jnp.ndarray, *, window: int = 0,
+                expert_slots: bool = False) -> Tuple:
     """tokens: (B,1); lengths: (B,) current fill of each cache row."""
     positions = lengths[:, None]
     if cfg.learned_positions:
         positions = jnp.clip(positions, 0, params["pos_embed"].shape[0] - 1)
     x = _embed_in(cfg, params, tokens, positions)
-    x, new_cache, _ = _run_stack(cfg, params, x, mode="decode",
-                                 lengths=lengths, cache=cache, window=window)
-    return _logits(cfg, params, x), new_cache
+    x, new_cache, _, slots = _run_stack(cfg, params, x, mode="decode",
+                                        lengths=lengths, cache=cache,
+                                        window=window)
+    logits = _logits(cfg, params, x)
+    return (logits, new_cache, slots) if expert_slots \
+        else (logits, new_cache)

@@ -22,7 +22,10 @@ of the paper's Fig. 11 measurements.
 Workloads:
   'forward'  — one full forward pass over a fixed-length input (the
                classification-style job of the paper's experiments)
-  'generate' — prefill(prompt_len) + gen_tokens KV-cache decode steps
+  'generate' — prefill(prompt_len) + gen_tokens KV-cache decode steps;
+               for MoE models the program also returns the slots each
+               held expert received, per step (prefill first) and MoE
+               layer, kept per batch in ``expert_slots``
 """
 from __future__ import annotations
 
@@ -70,7 +73,9 @@ class InferenceEngine:
 
     def __init__(self, cfg: ModelConfig, *, workload: str = "forward",
                  seq_len: int = 64, gen_tokens: int = 4,
-                 max_batch: int = 64, seed: int = 0):
+                 max_batch: int = 64, seed: int = 0, params=None):
+        """``params``: the model's weights; without them the engine makes
+        random ones from ``seed``."""
         self.cfg = cfg
         self.bundle: ModelBundle = build(cfg)
         self.workload = workload
@@ -78,8 +83,9 @@ class InferenceEngine:
         self.gen_tokens = gen_tokens
         self.max_batch = max_batch
         self.buckets = _buckets(max_batch)
-        key = jax.random.PRNGKey(seed)
-        self.params = self.bundle.init(key)
+        self.params = (self.bundle.init(jax.random.PRNGKey(seed))
+                       if params is None else params)
+        self.expert_slots: Optional[jnp.ndarray] = None
         self._fns: Dict[int, Callable] = {}
         self._rng = np.random.default_rng(seed)
         self._build_fns()
@@ -111,9 +117,11 @@ class InferenceEngine:
         elif self.workload == "generate":
             cache_len = self.seq_len + self.gen_tokens + 1
             gen_tokens = self.gen_tokens
+            moe = cfg.moe is not None
 
             def run(params, batch):
-                logits, cache = bundle.prefill(params, batch, cache_len)
+                logits, cache, *slots = bundle.prefill(
+                    params, batch, cache_len, expert_slots=moe)
                 tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
                 bsz = tok.shape[0]
                 offset = (cfg.encoder.n_ctx
@@ -123,14 +131,17 @@ class InferenceEngine:
 
                 def step(carry, _):
                     tok, cache, lengths = carry
-                    lg, cache = bundle.decode_step(params, tok, cache,
-                                                   lengths)
+                    lg, cache, *sl = bundle.decode_step(
+                        params, tok, cache, lengths, expert_slots=moe)
                     tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-                    return (tok, cache, lengths + 1), tok[:, 0]
+                    return (tok, cache, lengths + 1), (tok[:, 0], *sl)
 
-                (_, _, _), toks = jax.lax.scan(
+                (_, _, _), (toks, *steps) = jax.lax.scan(
                     step, (tok, cache, lengths), None, length=gen_tokens)
-                return toks.T
+                if not moe:
+                    return toks.T
+                # (1 + gen_tokens, MoE layers, held): prefill, then steps
+                return toks.T, jnp.concatenate([slots[0][None], steps[0]])
             fn = jax.jit(run)
             for b in self.buckets:
                 self._fns[b] = fn
@@ -160,6 +171,8 @@ class InferenceEngine:
                 out = self._fns[bb](self.params, batch)
                 jax.block_until_ready(out)
                 t = time.perf_counter() - t0
+        if isinstance(out, tuple):
+            self.expert_slots = out[1]
         return t
 
     def warmup(self) -> None:

@@ -194,3 +194,31 @@ def test_chain_solver_level_scan_compiles_under_x64(for_chip):
             for_chip(jax.ShapeDtypeStruct((64,), jnp.int32))).compile()
     assert compiled.memory_analysis() is not None
     cs._build_grid_kernel.cache_clear()
+
+
+def test_expert_layer_compiles_to_named_grouped_matmuls(for_chip):
+    """DeepSeek-V2-Lite's expert layer at its published widths, holding
+    8 of its 64 routed experts, at a decode step of 64 tokens: the
+    three grouped matmuls are Mosaic calls named ``ragged-dot``, the
+    name by which ``bench/metrics/moe_roofline.dsv2.py`` finds them in
+    the trace."""
+    import dataclasses
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.models import moe
+
+    cfg = get_config("deepseek-v2-lite-16b")
+    share = dataclasses.replace(cfg.moe, held_experts=8)
+    params = jax.eval_shape(lambda: moe.init_moe(
+        jax.random.PRNGKey(0), cfg.d_model, share, "swiglu", jnp.bfloat16))
+    x = jax.ShapeDtypeStruct((64, 1, cfg.d_model), jnp.bfloat16)
+    hlo = jax.jit(lambda p, x: moe.apply_moe(p, share, x, "swiglu")[::2]
+                  ).lower(jax.tree.map(for_chip, params),
+                          for_chip(x)).compile().as_text()
+    calls = re.findall(r"%(ragged-dot[\w.-]*) = .*custom_call_target="
+                       r"\"tpu_custom_call\"", hlo)
+    assert len([c for c in calls if "metadata" not in c]) == 3, calls
